@@ -96,7 +96,7 @@ def repro_section() -> str:
         out += ["", "(paper: flat until ~1 ms; Shisha still near-optimal beyond)", ""]
     kb = _load("kernels_bench")
     if kb:
-        out += ["### Kernel micro-bench (interpret mode — correctness + reference timing)", "", "```"]
+        out += ["### Kernel micro-bench (interpret mode on CPU — correctness + reference timing, not kernel times)", "", "```"]
         out += kb["rows"]
         out += ["```", ""]
     return "\n".join(out)

@@ -53,7 +53,14 @@ def conv2d_im2col(
     bk: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    """SAME-padded conv. x: [N, H, W, C]; w: [R, S, C, K] -> [N, HO, WO, K]."""
+    """SAME-padded conv. x: [N, H, W, C]; w: [R, S, C, K] -> [N, HO, WO, K].
+
+    Mosaic refuses the strided VMEM value slice of ``stride > 1``
+    (``vector.extract_strided_slice`` takes unit strides only), so a strided
+    conv runs only in interpret mode.
+    """
+    if stride > 1 and not interpret:
+        raise ValueError(f"conv2d_im2col: stride {stride} > 1 does not compile for the TPU; use interpret=True")
     n, h, wid, c = x.shape
     r, s, c2, k = w.shape
     assert c == c2, (x.shape, w.shape)

@@ -1,39 +1,15 @@
-"""jit'd public wrappers around the Pallas kernels.
+"""Public entry points of the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites work in CPU
-tests/examples (Pallas interpret mode executes the kernel body in Python)
-and compile to real Mosaic kernels on TPU.
+Each kernel compiles to a Mosaic kernel for the TPU.  ``interpret``
+defaults to False everywhere; a CPU caller (tests, micro-benchmarks) asks
+for Pallas interpret mode explicitly with ``interpret=True``.
 """
 
 from __future__ import annotations
 
-import jax
+from .flash_attention import flash_attention
+from .gemm import gemm
+from .im2col_conv import conv2d_im2col
+from .ssd_scan import ssd_scan
 
-from .flash_attention import flash_attention as _flash
-from .gemm import gemm as _gemm
-from .im2col_conv import conv2d_im2col as _conv
-from .ssd_scan import ssd_scan as _ssd
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def gemm(a, b, **kw):
-    kw.setdefault("interpret", _default_interpret())
-    return _gemm(a, b, **kw)
-
-
-def conv2d_im2col(x, w, *, stride: int = 1, **kw):
-    kw.setdefault("interpret", _default_interpret())
-    return _conv(x, w, stride=stride, **kw)
-
-
-def flash_attention(q, k, v, *, causal: bool = True, **kw):
-    kw.setdefault("interpret", _default_interpret())
-    return _flash(q, k, v, causal=causal, **kw)
-
-
-def ssd_scan(x, dt, A, B, C, *, chunk: int = 64, **kw):
-    kw.setdefault("interpret", _default_interpret())
-    return _ssd(x, dt, A, B, C, chunk=chunk, **kw)
+__all__ = ["conv2d_im2col", "flash_attention", "gemm", "ssd_scan"]

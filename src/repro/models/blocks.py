@@ -19,7 +19,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 from .lm_common import LMConfig, cstr_act, cstr_custom, cstr_heads, rms_norm, rotary
 
 # ---------------------------------------------------------------------------
@@ -296,7 +295,7 @@ def moe_ffn(cfg: LMConfig, p: dict, x: jax.Array, mesh=None, dp_axes=("data",), 
         aux = jax.lax.pmean(aux, dp_axes)
         return y, aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(dp_axes, None, None), w_specs),

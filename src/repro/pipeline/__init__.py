@@ -1,16 +1,11 @@
 """Shisha-scheduled pipeline runtime (shard_map + ppermute micro-batching)."""
 
 from .hetero import EPDerates, tpu_platform_from_mesh
-from .runtime import (
-    MeasuringEvaluator,
-    PipelineRunner,
-    pipeline_throughput,
-)
+from .runtime import MeasuringEvaluator, PipelineRunner
 
 __all__ = [
     "EPDerates",
     "MeasuringEvaluator",
     "PipelineRunner",
-    "pipeline_throughput",
     "tpu_platform_from_mesh",
 ]

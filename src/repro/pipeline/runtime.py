@@ -8,15 +8,10 @@ stages with ``jax.lax.ppermute`` — GPipe-style fill/steady/drain, built
 with shard_map so every transfer is an explicit neighbour permute (the
 paper's inter-chiplet link).
 
-Two oracles close the online-tuning loop:
-
-  * :class:`MeasuringEvaluator` — times each (layer, EP) pair on the real
-    device (jitted, synced) and scales by the EP derate (hetero.py).  This
-    is the paper's "runtime performance value" — Algorithm 2 consumes it
-    exactly like the gem5 database.
-  * :func:`pipeline_throughput` — runs the actual pipelined computation
-    and measures end-to-end images/s, used to validate that the schedule
-    Shisha picked is the schedule that actually runs fastest.
+:class:`MeasuringEvaluator` closes the online-tuning loop: it times each
+(layer, EP) pair on the real device (jitted, synced) and scales by the EP
+derate (hetero.py).  This is the paper's "runtime performance value" —
+Algorithm 2 consumes it exactly like the gem5 database.
 """
 
 from __future__ import annotations
@@ -31,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..core.config import PipelineConfig
 from ..core.cost_model import Layer
 from ..core.evaluator import AnalyticEvaluator
@@ -125,6 +119,9 @@ class PipelineRunner:
             return stage_fn
 
         self._stage_fns = [make_stage(a, b) for a, b in bounds]
+        # one jitted program per runner: a fresh jax.jit on every call
+        # would trace and compile it again each time
+        self._run = jax.jit(self._pipelined)
 
     def _pipelined(self, micro: jax.Array) -> jax.Array:
         """micro: [n_micro, ...activation] replicated. Returns outputs."""
@@ -165,7 +162,7 @@ class PipelineRunner:
             )
             return outs
 
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=P(),  # microbatches replicated; stages own the compute
@@ -175,16 +172,5 @@ class PipelineRunner:
 
     def run(self, micro: jax.Array) -> jax.Array:
         """micro: [n_micro, ...]. Returns [n_micro, ...] final activations."""
-        return jax.jit(self._pipelined)(micro)
+        return self._run(micro)
 
-
-def pipeline_throughput(runner: PipelineRunner, micro: jax.Array, reps: int = 3) -> float:
-    """Measured end-to-end microbatches/second of the real pipeline."""
-    fn = jax.jit(runner._pipelined)
-    jax.block_until_ready(fn(micro))
-    best = np.inf
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(micro))
-        best = min(best, time.perf_counter() - t0)
-    return runner.n_micro / best

@@ -23,7 +23,7 @@ TOL = {jnp.float32: dict(rtol=2e-4, atol=2e-4), jnp.bfloat16: dict(rtol=6e-2, at
 def test_gemm(m, k, n, dtype):
     a = _rand(jax.random.fold_in(KEY, m), (m, k), dtype)
     b = _rand(jax.random.fold_in(KEY, n), (k, n), dtype)
-    y = ops.gemm(a, b, bm=64, bn=64, bk=128)
+    y = ops.gemm(a, b, bm=64, bn=64, bk=128, interpret=True)
     yr = ref.gemm_ref(a, b)
     np.testing.assert_allclose(
         np.asarray(y, np.float32), np.asarray(yr, np.float32), **TOL[dtype]
@@ -36,7 +36,7 @@ def test_gemm(m, k, n, dtype):
 def test_conv2d(stride, r, s, dtype):
     x = _rand(jax.random.fold_in(KEY, r), (2, 12, 12, 8), dtype)
     w = _rand(jax.random.fold_in(KEY, s), (r, s, 8, 24), dtype)
-    y = ops.conv2d_im2col(x, w, stride=stride, bk=16)
+    y = ops.conv2d_im2col(x, w, stride=stride, bk=16, interpret=True)
     yr = ref.conv2d_ref(x, w, stride=stride)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=3e-4, atol=3e-4)
 
@@ -49,7 +49,7 @@ def test_flash_attention(causal, h, kvh, s):
     q = _rand(jax.random.fold_in(KEY, h), (2, h, s, d), jnp.float32)
     k = _rand(jax.random.fold_in(KEY, kvh), (2, kvh, s, d), jnp.float32)
     v = _rand(jax.random.fold_in(KEY, s), (2, kvh, s, d), jnp.float32)
-    y = ops.flash_attention(q, k, v, causal=causal, bq=32, bk=32)
+    y = ops.flash_attention(q, k, v, causal=causal, bq=32, bk=32, interpret=True)
     yr = ref.attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=2e-4, atol=2e-4)
 
@@ -64,7 +64,7 @@ def test_ssd_scan(chunk, h, p, n):
     A = -jnp.exp(_rand(ks[2], (h,), jnp.float32) * 0.5)
     B = _rand(ks[3], (b, l, n), jnp.float32)
     C = _rand(ks[4], (b, l, n), jnp.float32)
-    y = ops.ssd_scan(x, dt, A, B, C, chunk=chunk)
+    y = ops.ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=True)
     yr = ref.ssd_ref(x, dt, A, B, C)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=2e-3, atol=2e-3)
 
