@@ -23,9 +23,8 @@ budget, next to the throughput price.
 
 The full payload lands in ``experiments/benchmarks/power_sweep.json`` and
 the acceptance cell's headline (tightest swept cap) additionally in
-``BENCH_power_sweep.json`` at the repo root, mirroring
-``BENCH_selfbench.json``; both are strict JSON (an uncapped model reports
-``cap_w`` as ``null``, never ``inf``).  Everything here is deterministic:
+``BENCH_power_sweep.json`` at the repo root; both are strict JSON (an
+uncapped model reports ``cap_w`` as ``null``, never ``inf``).  Everything here is deterministic:
 database oracle, seeded traffic, seeded thermal parameter jitter.
 """
 

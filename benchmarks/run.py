@@ -44,7 +44,7 @@ def main() -> None:
 
     from . import fig4_convergence, fig5_quality, fig6_seed, fig7_heuristics, fig9_latency
     from . import chaos_sweep, fig9_interconnect, kernels_bench, power_sweep, roofline
-    from . import selfbench, serve_sim
+    from . import serve_sim
 
     figures = {
         "fig4": fig4_convergence.run,
@@ -58,7 +58,6 @@ def main() -> None:
         "roofline": roofline.run,
         "serve_sim": lambda: serve_sim.run(quick=args.quick),
         "multitenant_drift": lambda: serve_sim.run_multitenant_drift(quick=args.quick),
-        "selfbench": lambda: selfbench.run(quick=args.quick),
         "power_sweep": lambda: power_sweep.run(quick=args.quick),
         "chaos_sweep": lambda: chaos_sweep.run(quick=args.quick),
     }

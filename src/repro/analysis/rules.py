@@ -97,8 +97,7 @@ class WallClockRule(Rule):
     exports all advance on the *simulated* clock; a stray
     ``time.time()`` makes seeded reruns diverge and un-pins every BENCH
     artifact.  Real-hardware paths (``launch/``, ``pipeline/runtime.py``,
-    ``benchmarks/``) are allowlisted; ``telemetry.timed`` is the one
-    sanctioned wall-clock instrument and carries explicit pragmas.
+    ``benchmarks/``) are allowlisted.
     """
 
     name = "wall-clock"
@@ -141,7 +140,7 @@ class WallClockRule(Rule):
                 yield self.finding(
                     ctx, node,
                     f"{origin}() reads the wall clock on a simulated path; "
-                    "use the simulated clock (or telemetry.timed for profiling)",
+                    "use the simulated clock",
                 )
             elif (
                 origin.startswith("datetime.")

@@ -196,6 +196,10 @@ def canonical_pipeline_apply(model: CNNModel, params, input_shape: tuple[int, in
     Padding + exact cropping (never resizing through the pad) keeps the
     pipelined result bit-identical to sequential execution.
 
+    The pad, the slice back to a layer's real shape and the final crop
+    carry the ``shisha.canon`` named scope, inside whatever scope the caller
+    wraps a layer in.
+
     Returns (apply_fn, to_canon, crop_out, canon_shape).
     """
     specs = model.specs
@@ -205,10 +209,11 @@ def canonical_pipeline_apply(model: CNNModel, params, input_shape: tuple[int, in
     canon = (hc, wc, cc)
 
     def to_canon(x):
-        return jnp.pad(
-            x,
-            ((0, 0), (0, hc - x.shape[1]), (0, wc - x.shape[2]), (0, cc - x.shape[3])),
-        )
+        with jax.named_scope("shisha.canon"):
+            return jnp.pad(
+                x,
+                ((0, 0), (0, hc - x.shape[1]), (0, wc - x.shape[2]), (0, cc - x.shape[3])),
+            )
 
     def shape_into(i):
         if i == 0:
@@ -218,13 +223,15 @@ def canonical_pipeline_apply(model: CNNModel, params, input_shape: tuple[int, in
 
     def apply_fn(i, xc):
         h, w, c = shape_into(i)
-        x = xc[:, :h, :w, :c]
+        with jax.named_scope("shisha.canon"):
+            x = xc[:, :h, :w, :c]
         y = model.apply_layer(i, params[i], x)
         return to_canon(y)
 
     def crop_out(xc):
         sp = specs[-1]
-        return xc[..., : sp.h_out, : sp.w_out, : sp.k]
+        with jax.named_scope("shisha.canon"):
+            return xc[..., : sp.h_out, : sp.w_out, : sp.k]
 
     return apply_fn, to_canon, crop_out, canon
 

@@ -47,7 +47,10 @@ class EPDerates:
 
 
 def tpu_platform_from_mesh(n_stages: int, chips_per_stage: int = 8, slow_fraction: float = 0.5) -> Platform:
-    """A Platform whose EPs are slices of a TPU mesh (DESIGN.md §2 mapping)."""
+    """A Platform whose EPs are slices of a TPU mesh: one EP per pipeline
+    stage, each a slice of ``chips_per_stage`` chips, the first
+    ``slow_fraction`` of them modelled slow: the paper's chiplets, mapped
+    onto slices."""
     n_slow = int(n_stages * slow_fraction)
     eps = [
         tpu_slice_ep(f"slice{i}", chips_per_stage, fast=(i >= n_slow))

@@ -24,6 +24,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.config import PipelineConfig
@@ -55,16 +56,21 @@ class MeasuringEvaluator(AnalyticEvaluator):
     def __post_init__(self):
         self.derates = EPDerates.from_platform(self.platform)
         self._measured: list[float] = []
-        for fn, args in zip(self.layer_fns, self.layer_args):
-            jf = jax.jit(fn)
-            out = jf(*args)
-            jax.block_until_ready(out)  # compile + warm
-            best = np.inf
-            for _ in range(self.reps):
-                t0 = time.perf_counter()
-                jax.block_until_ready(jf(*args))
-                best = min(best, time.perf_counter() - t0)
-            self._measured.append(best)
+        # profiler spans around, never inside, the timed calls: with the
+        # profiler off each costs about a microsecond
+        with TraceAnnotation("shisha.measure"):
+            for i, (fn, args) in enumerate(zip(self.layer_fns, self.layer_args)):
+                with TraceAnnotation("shisha.measure.warm", layer=i):
+                    jf = jax.jit(fn)
+                    out = jf(*args)
+                    jax.block_until_ready(out)  # compile + warm
+                best = np.inf
+                with TraceAnnotation("shisha.measure.timed", layer=i, calls=self.reps):
+                    for _ in range(self.reps):
+                        t0 = time.perf_counter()
+                        jax.block_until_ready(jf(*args))
+                        best = min(best, time.perf_counter() - t0)
+                self._measured.append(best)
 
     def layer_time(self, layer: Layer, ep_idx: int) -> float:  # type: ignore[override]
         li = list(self.layers).index(layer)
@@ -97,6 +103,10 @@ class PipelineRunner:
     ``apply_layer(i, x)`` must map a canonical activation shape to itself
     (the CNN model resizes internally; LM blocks are shape-preserving), so
     stages of different depth stay branch-compatible under lax.switch.
+
+    The program's own steps carry ``shisha.*`` named scopes (``init``,
+    ``inject``, ``emit``, ``ship``, ``gather``), which change only op-name
+    metadata; each ``run`` is a ``shisha.run`` profiler span.
     """
 
     mesh: Mesh
@@ -135,31 +145,36 @@ class PipelineRunner:
         def local(micro_loc):
             sid = jax.lax.axis_index("stage")
             act_shape = micro_loc.shape[1:]
-            buf = jnp.zeros(act_shape, micro_loc.dtype)
-            outs = jnp.zeros((n_micro,) + act_shape, micro_loc.dtype)
+            with jax.named_scope("shisha.init"):
+                buf = jnp.zeros(act_shape, micro_loc.dtype)
+                outs = jnp.zeros((n_micro,) + act_shape, micro_loc.dtype)
 
             def tick(carry, t):
                 buf, outs = carry
                 # stage 0 ingests microbatch t (when in range)
-                take = jnp.clip(t, 0, n_micro - 1)
-                inject = micro_loc[take]
-                x = jnp.where(sid == 0, jnp.where(t < n_micro, inject, buf * 0), buf)
+                with jax.named_scope("shisha.inject"):
+                    take = jnp.clip(t, 0, n_micro - 1)
+                    inject = micro_loc[take]
+                    x = jnp.where(sid == 0, jnp.where(t < n_micro, inject, buf * 0), buf)
                 y = jax.lax.switch(sid, stage_fns, x)
                 # last stage emits microbatch t - (n_stages - 1)
-                emit_idx = jnp.clip(t - (n_stages - 1), 0, n_micro - 1)
-                valid = (t - (n_stages - 1) >= 0) & (sid == n_stages - 1)
-                outs = jax.lax.dynamic_update_index_in_dim(
-                    outs, jnp.where(valid, y, outs[emit_idx]), emit_idx, 0
-                )
+                with jax.named_scope("shisha.emit"):
+                    emit_idx = jnp.clip(t - (n_stages - 1), 0, n_micro - 1)
+                    valid = (t - (n_stages - 1) >= 0) & (sid == n_stages - 1)
+                    outs = jax.lax.dynamic_update_index_in_dim(
+                        outs, jnp.where(valid, y, outs[emit_idx]), emit_idx, 0
+                    )
                 # ship activations one stage forward
-                buf = jax.lax.ppermute(y, "stage", fwd)
+                with jax.named_scope("shisha.ship"):
+                    buf = jax.lax.ppermute(y, "stage", fwd)
                 return (buf, outs), None
 
             (buf, outs), _ = jax.lax.scan(tick, (buf, outs), jnp.arange(ticks))
             # bring results from the last stage to every shard (replicated out)
-            outs = jax.lax.psum(
-                jnp.where(sid == n_stages - 1, outs, jnp.zeros_like(outs)), "stage"
-            )
+            with jax.named_scope("shisha.gather"):
+                outs = jax.lax.psum(
+                    jnp.where(sid == n_stages - 1, outs, jnp.zeros_like(outs)), "stage"
+                )
             return outs
 
         return jax.shard_map(
@@ -172,5 +187,6 @@ class PipelineRunner:
 
     def run(self, micro: jax.Array) -> jax.Array:
         """micro: [n_micro, ...]. Returns [n_micro, ...] final activations."""
-        return self._run(micro)
+        with TraceAnnotation("shisha.run"):
+            return self._run(micro)
 

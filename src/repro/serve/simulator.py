@@ -130,12 +130,10 @@ class EventLoop:
         self._drain: list = []
         self._i = 0  # next undispatched index into _drain
         self._near: list = []
-        #: events dispatched over the loop's lifetime — the denominator of
-        #: ``benchmarks/selfbench.py``'s simulated-events/sec figure
+        #: events dispatched over the loop's lifetime
         self.n_dispatched = 0
         #: live telemetry session or None; when live, ``run`` keeps
-        #: ``telemetry.now`` on the simulated clock and wall-profiles the
-        #: dispatch loop under the ``event_loop.run`` scope
+        #: ``telemetry.now`` on the simulated clock
         self.telemetry = live(telemetry)
 
     def push(self, t: float, kind: int, owner, payload) -> None:
@@ -180,12 +178,7 @@ class EventLoop:
         Peeks before consuming: an event past ``horizon`` stays queued, so
         windowed/incremental callers never lose it.
         """
-        tl = self.telemetry
-        if tl is None:
-            self._advance(horizon, None)
-            return
-        with tl.timed("event_loop.run"):
-            self._advance(horizon, tl)
+        self._advance(horizon, self.telemetry)
 
     def _advance(self, horizon: float, tl) -> None:
         near = self._near
@@ -269,8 +262,7 @@ class HeapEventLoop:
     Same API and — pinned by the equivalence suite — the same dispatch
     sequence as :class:`EventLoop`, paying one heap sift per event.  Use
     it to cross-check engine changes bit-for-bit
-    (``tests/test_event_engine.py``, ``benchmarks/selfbench.py``'s legacy
-    arms) or to bisect a suspected engine bug.  The historical
+    (``tests/test_event_engine.py``) or to bisect a suspected engine bug.  The historical
     beyond-horizon bug is fixed here too: ``run`` peeks at the heap head
     before popping, so windowed callers never lose an event.
     """
@@ -303,12 +295,11 @@ class HeapEventLoop:
                 self.n_dispatched += 1
                 owner._dispatch(t, kind, payload)
             return
-        with tl.timed("event_loop.run"):
-            while heap and heap[0][0] <= horizon:
-                t, kind, _seq, owner, payload = heappop(heap)
-                self.n_dispatched += 1
-                tl.now = t
-                owner._dispatch(t, kind, payload)
+        while heap and heap[0][0] <= horizon:
+            t, kind, _seq, owner, payload = heappop(heap)
+            self.n_dispatched += 1
+            tl.now = t
+            owner._dispatch(t, kind, payload)
 
 
 # slots: requests and stages are the per-event hot allocations (one Request

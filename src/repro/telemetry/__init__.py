@@ -12,10 +12,8 @@ Three parts, all zero-dependency and deterministic under seeded runs:
     re-tune exploration windows, repartition/revival decisions, per-window
     fabric flow injections) exportable as JSONL and as Chrome trace-event
     JSON loadable in Perfetto — tenants as processes, EPs/links as tracks.
-  * :mod:`.core` — the :class:`Telemetry` facade tying both to the
-    wall-clock :meth:`~repro.telemetry.core.Telemetry.timed` profiling hooks
-    that ``benchmarks/selfbench.py`` turns into a simulated-events/sec
-    trajectory (``BENCH_selfbench.json``).
+  * :mod:`.core` — the :class:`Telemetry` facade tying both into one
+    session that every layer of a run records into.
 
 Everything is **off by default**: every instrumented constructor accepts
 ``telemetry=None`` (or the explicit no-op :data:`NULL` sink) and the hot

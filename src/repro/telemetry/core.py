@@ -1,10 +1,9 @@
 """The :class:`Telemetry` facade the simulation layers record into.
 
 One ``Telemetry`` object is one observation session: a
-:class:`~repro.telemetry.metrics.MetricsRegistry`, a
-:class:`~repro.telemetry.tracer.SpanTracer` and a wall-clock profile for
-the :meth:`Telemetry.timed` hooks, shared by every layer of a run (serving
-lanes, co-simulator, tuner traces, fabric).  Pass it to
+:class:`~repro.telemetry.metrics.MetricsRegistry` and a
+:class:`~repro.telemetry.tracer.SpanTracer`, shared by every layer of a run
+(serving lanes, co-simulator, tuner traces, fabric).  Pass it to
 ``ServingSimulator(..., telemetry=...)`` / ``co_serve(..., telemetry=...)``
 and export afterwards.
 
@@ -16,53 +15,23 @@ an object rather than ``None`` — it accepts every call, records nothing,
 and reports ``enabled = False``, which the constructors normalize to the
 same disabled fast path.
 
-Clock discipline: everything *exported* (metrics observations, span/instant
+Clock discipline: everything recorded (metrics observations, span/instant
 timestamps) lives on the simulated clock, so seeded runs export
-byte-identical artifacts.  The only wall-clock state is :attr:`Telemetry
-.profile`, fed by ``timed()`` scopes around real hot loops; it exists for
-``benchmarks/selfbench.py`` (simulated-events/sec) and is deliberately kept
-out of every trace/JSONL export.
+byte-identical artifacts.  Time on the chip is traced by JAX's profiler
+instead (the program's ``shisha.*`` spans and scopes).
 """
 
 from __future__ import annotations
 
 import json
-import time
 from pathlib import Path
 
 from .metrics import MetricsRegistry
 from .tracer import SpanTracer
 
 
-class _Timer:
-    """Accumulates perf_counter wall time into a profile slot."""
-
-    __slots__ = ("_profile", "_scope", "_t0")
-
-    def __init__(self, profile: dict, scope: str):
-        self._profile = profile
-        self._scope = scope
-
-    def __enter__(self):
-        # the one sanctioned wall-clock instrument: timed() profiles real
-        # elapsed time by design and its readings are never exported into
-        # seeded artifacts (see profile_snapshot)
-        self._t0 = time.perf_counter()  # shisha: allow(wall-clock)
-        return self
-
-    def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0  # shisha: allow(wall-clock)
-        slot = self._profile.get(self._scope)
-        if slot is None:
-            self._profile[self._scope] = [1, dt]
-        else:
-            slot[0] += 1
-            slot[1] += dt
-        return False
-
-
 class Telemetry:
-    """Live observation session: metrics + spans + wall-clock profile."""
+    """Live observation session: metrics + spans."""
 
     #: constructors treat a telemetry object with ``enabled = False`` (see
     #: :class:`NullTelemetry`) exactly like ``None``
@@ -71,8 +40,6 @@ class Telemetry:
     def __init__(self):
         self.registry = MetricsRegistry()
         self.tracer = SpanTracer()
-        #: scope -> [calls, wall seconds]; wall-clock by design, never exported
-        self.profile: dict[str, list] = {}
         #: current simulated time, maintained by the event loop that owns
         #: this session (convenience for recorders without a timestamp)
         self.now = 0.0
@@ -99,19 +66,6 @@ class Telemetry:
     def counter_track(self, name: str, ts: float, value: float, **kw) -> None:
         """One sample of a Perfetto counter track (temperature, watts)."""
         self.tracer.counter(name, ts, value, **kw)
-
-    # -- profiling hooks -----------------------------------------------------
-
-    def timed(self, scope: str) -> _Timer:
-        """``with telemetry.timed("event_loop.run"): ...`` — wall profiling."""
-        return _Timer(self.profile, scope)
-
-    def profile_snapshot(self) -> dict:
-        """scope -> {calls, wall_s}, sorted; for benchmark payloads only."""
-        return {
-            scope: {"calls": calls, "wall_s": wall}
-            for scope, (calls, wall) in sorted(self.profile.items())
-        }
 
     # -- exports (simulated-clock artifacts only) ----------------------------
 
@@ -152,18 +106,7 @@ class _NullMetric:
         pass
 
 
-class _NullTimer:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
 _NULL_METRIC = _NullMetric()
-_NULL_TIMER = _NullTimer()
 
 
 class NullTelemetry(Telemetry):
@@ -193,9 +136,6 @@ class NullTelemetry(Telemetry):
 
     def counter_track(self, name: str, ts: float, value: float, **kw) -> None:
         pass
-
-    def timed(self, scope: str) -> _NullTimer:
-        return _NULL_TIMER
 
 
 #: shared no-op sink; safe to pass anywhere a Telemetry is accepted

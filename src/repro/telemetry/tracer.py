@@ -38,7 +38,8 @@ class TraceEvent:
     Slotted: serving hot paths construct one of these per batch and per
     completed request (they append straight to ``SpanTracer.events`` — see
     ``ServingSimulator._bind_metrics``), so construction cost is part of
-    the instrumented/bare overhead ratio the selfbench floor test pins."""
+    the instrumented/bare overhead ratio that
+    ``tests/test_telemetry.py::test_serve_telemetry_overhead_floor`` pins."""
 
     ts: float  # simulated seconds
     name: str

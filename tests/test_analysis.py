@@ -192,7 +192,7 @@ def test_every_pragma_in_src_is_load_bearing():
                 "suppresses nothing — the gate would not notice its deletion"
             )
             checked += 1
-    assert checked >= 3, "expected at least the known pragmas in src/"
+    assert checked >= 1, "expected at least the known pragma in src/"
 
 
 def test_report_is_deterministic():

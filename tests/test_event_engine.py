@@ -19,15 +19,12 @@ dispatch contract is *bit-for-bit* preserved; this module is that pin:
     produce field-for-field identical results under either engine.
   * **throughput floor** — the drain engine must hold >= 2.5x the legacy
     heap on raw no-op dispatch (relative, in-process, so CI machine speed
-    cancels), and the committed ``BENCH_selfbench.json`` must witness the
-    >= 3x headline speedup.
+    cancels).
 """
 
-import json
 import math
 import random
 import time
-from pathlib import Path
 
 import pytest
 
@@ -44,7 +41,6 @@ from repro.serve import (
 from repro.serve.simulator import EventLoop, HeapEventLoop
 from repro.serve.traffic import _rng
 
-ROOT = Path(__file__).resolve().parent.parent
 
 ENGINES = [EventLoop, HeapEventLoop]
 ENGINE_IDS = ["drain", "heap"]
@@ -433,14 +429,3 @@ def test_drain_engine_dispatch_floor_vs_legacy():
         new = min(new, arm(EventLoop))
         old = min(old, arm(HeapEventLoop))
     assert old / new >= 2.5, f"drain engine only {old / new:.2f}x the legacy heap"
-
-
-def test_selfbench_artifact_witnesses_engine_speedup():
-    """The committed payload must pin the >= 3x raw-dispatch headline and
-    carry the legacy arm it was measured against."""
-    data = json.loads((ROOT / "BENCH_selfbench.json").read_text())
-    el = data["event_loop"]
-    assert el["legacy_heap"]["events_per_s"] > 0
-    assert el["speedup_vs_legacy"] >= 3.0
-    assert "legacy_heap" in data["serve"]
-    assert data["serve"]["speedup_vs_legacy"] > 0

@@ -177,9 +177,8 @@ def test_event_loop_noop_dispatch_floor():
 def test_serve_telemetry_overhead_floor():
     """Instrumented serving must stay close to the bare run.
 
-    Mirrors ``benchmarks.selfbench.bench_serve``: arms are warmed once and
-    then *interleaved* best-of, so machine-load drift cancels out of the
-    ratio instead of biasing it.  The bound is deliberately loose — the
+    Arms are warmed once and then *interleaved* best-of, so machine-load
+    drift cancels out of the ratio instead of biasing it.  The bound is deliberately loose — the
     optimized hot path (direct ``TraceEvent`` appends from the simulator)
     measures ~1.4-1.65x on the PR 9 drain engine (the faster bare loop
     shrank the denominator; it was ~1.4x on the legacy heap engine),
