@@ -16,6 +16,7 @@ layers replicated (channels chained across repeats) to reach 18 layers.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Sequence
 
 import jax
@@ -188,50 +189,66 @@ class CNNModel:
         return self.apply_range(params, x, 0, len(self.specs), **kw)
 
 
+# a TPU tiles the two minor dims of an array (8, 128): an image held as rows
+# of 128 lanes is padded and sliced in whole rows
+CANON_LANES = 128
+
+
+def _rows(n: int) -> int:
+    return -(-n // CANON_LANES)
+
+
 def canonical_pipeline_apply(model: CNNModel, params, input_shape: tuple[int, int, int]):
     """Shape-uniform layer application for the stage pipeline.
 
     Pipeline stages must be branch-compatible under lax.switch, so every
-    layer maps a canonical zero-padded activation [B, Hc, Wc, Cc] to itself.
-    Padding + exact cropping (never resizing through the pad) keeps the
-    pipelined result bit-identical to sequential execution.
+    layer maps one canonical activation to itself: an image flattened in
+    row-major (h, w, c) order, zero-padded to ``R * 128`` elements and held as
+    [B, R, 128].  ``R * 128`` is the largest element count of any activation
+    that can sit on a stage boundary (the input and every layer's output),
+    rounded up to 128.  Each layer slices its real-shaped input out of the
+    front of the flat image, so the pipelined result is bit-identical to
+    sequential execution.
 
     The pad, the slice back to a layer's real shape and the final crop
     carry the ``shisha.canon`` named scope, inside whatever scope the caller
     wraps a layer in.
 
-    Returns (apply_fn, to_canon, crop_out, canon_shape).
+    Returns (apply_fn, to_canon, crop_out, canon_shape): ``to_canon`` takes
+    [B, h, w, c], ``crop_out`` takes [..., R, 128] and gives the last layer's
+    [..., h, w, k], and ``canon_shape`` is (R, 128).
     """
     specs = model.specs
-    hc = max([input_shape[0]] + [sp.h_out * sp.stride for sp in specs] + [sp.h_out for sp in specs])
-    wc = max([input_shape[1]] + [sp.w_out * sp.stride for sp in specs] + [sp.w_out for sp in specs])
-    cc = max([input_shape[2]] + [sp.c_in for sp in specs] + [sp.k for sp in specs])
-    canon = (hc, wc, cc)
+    # boundary[i] is the activation entering layer i; the last is the output
+    boundary = [tuple(input_shape)] + [(sp.h_out, sp.w_out, sp.k) for sp in specs]
+    rows = _rows(max(map(math.prod, boundary)))
+    canon = (rows, CANON_LANES)
 
+    # Both directions move whole 128-lane rows: padding or slicing the flat
+    # image at its full length lets the TPU compiler lay the flat dim out
+    # major, which makes the copy of a lane-sparse input (c = 3) far slower.
     def to_canon(x):
+        b, n = x.shape[0], x[0].size
+        r = _rows(n)
         with jax.named_scope("shisha.canon"):
-            return jnp.pad(
-                x,
-                ((0, 0), (0, hc - x.shape[1]), (0, wc - x.shape[2]), (0, cc - x.shape[3])),
-            )
+            flat = jnp.pad(x.reshape(b, n), ((0, 0), (0, r * CANON_LANES - n)))
+            return jnp.pad(flat.reshape(b, r, CANON_LANES), ((0, 0), (0, rows - r), (0, 0)))
 
-    def shape_into(i):
-        if i == 0:
-            return input_shape
-        sp = specs[i - 1]
-        return (sp.h_out, sp.w_out, sp.k)
+    def from_canon(xc, shape):
+        lead = xc.shape[:-2]
+        n = math.prod(shape)
+        r = _rows(n)
+        return xc[..., :r, :].reshape(*lead, r * CANON_LANES)[..., :n].reshape(*lead, *shape)
 
     def apply_fn(i, xc):
-        h, w, c = shape_into(i)
         with jax.named_scope("shisha.canon"):
-            x = xc[:, :h, :w, :c]
+            x = from_canon(xc, boundary[i])
         y = model.apply_layer(i, params[i], x)
         return to_canon(y)
 
     def crop_out(xc):
-        sp = specs[-1]
         with jax.named_scope("shisha.canon"):
-            return xc[..., : sp.h_out, : sp.w_out, : sp.k]
+            return from_canon(xc, boundary[-1])
 
     return apply_fn, to_canon, crop_out, canon
 

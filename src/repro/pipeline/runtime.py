@@ -100,9 +100,12 @@ class MeasuringEvaluator(AnalyticEvaluator):
 class PipelineRunner:
     """Runs a layer chain as an N-stage microbatched pipeline.
 
-    ``apply_layer(i, x)`` must map a canonical activation shape to itself
-    (the CNN model resizes internally; LM blocks are shape-preserving), so
-    stages of different depth stay branch-compatible under lax.switch.
+    ``apply_layer(i, x)`` must map one activation shape to itself, so stages
+    of different depth stay branch-compatible under lax.switch: for a CNN
+    that is ``canonical_pipeline_apply``'s flat canonical, each image padded
+    to the largest activation on any stage boundary; LM blocks are
+    shape-preserving.  Every buffer the runner carries (``micro``, ``buf``,
+    ``outs``) has that shape per microbatch.
 
     The program's own steps carry ``shisha.*`` named scopes (``init``,
     ``inject``, ``emit``, ``ship``, ``gather``), which change only op-name

@@ -13,6 +13,7 @@ described inside a fixture, never while a module is imported.
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +27,7 @@ from repro.models.cnn import canonical_pipeline_apply, make_cnn, network_layers
 from repro.pipeline import PipelineRunner
 from repro.pipeline.hetero import tpu_platform_from_mesh
 
-HBM_BYTES = 16e9  # one v5e chip
+RUNNER_BYTES = 0.5e9  # of one v5e chip's 16 GB: weights and flat canonical buffers
 
 
 @pytest.fixture(scope="module")
@@ -81,7 +82,15 @@ def test_conv_kernel_refuses_stride_two_stem(one_chip):
 
 @pytest.mark.parametrize("n_stages", [1, 4])
 def test_resnet50_pipeline_fits_one_chip(topo, n_stages):
-    """The full-width 224x224 PipelineRunner program, 8 microbatches of 1 image."""
+    """The full-width 224x224 PipelineRunner program, 8 microbatches of 1 image.
+
+    Its buffers hold each image as the flat canonical, so the program is about
+    the weights (91 MB) and 8 of ResNet-50's largest activations (3.2 MB each)
+    in and out.  Inside a stage each layer's pad meets the next layer's slice
+    and folds away: a stage keeps at most four of the canonical's pads (its
+    output's, to whole rows and to all rows, and copies the compiler makes
+    of them or merges with the first conv's padding), not two a layer.
+    """
     model = make_cnn("resnet50")
     in_shape = (224, 224, 3)
     platform = tpu_platform_from_mesh(4, chips_per_stage=1)
@@ -103,6 +112,8 @@ def test_resnet50_pipeline_fits_one_chip(topo, n_stages):
         compiled = jax.jit(program).lower(params, micro).compile()
     mem = compiled.memory_analysis()
     per_device = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
-    assert per_device < HBM_BYTES, (n_stages, per_device)
+    assert per_device < RUNNER_BYTES, (n_stages, per_device)
+    text = compiled.as_text()
+    assert len(re.findall(r"= \S+ pad\(.*shisha\.canon", text)) <= 4 * n_stages
     if n_stages > 1:
-        assert "collective-permute" in compiled.as_text()
+        assert "collective-permute" in text
