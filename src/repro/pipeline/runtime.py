@@ -102,10 +102,12 @@ class PipelineRunner:
 
     ``apply_layer(i, x)`` must map one activation shape to itself, so stages
     of different depth stay branch-compatible under lax.switch: for a CNN
-    that is ``canonical_pipeline_apply``'s flat canonical, each image padded
-    to the largest activation on any stage boundary; LM blocks are
-    shape-preserving.  Every buffer the runner carries (``micro``, ``buf``,
-    ``outs``) has that shape per microbatch.
+    that is ``canonical_pipeline_apply``'s flat canonical, which holds a
+    boundary's whole live set (every tensor a later layer still reads: the
+    previous output alone in a chain, several across a residual block or a
+    route) at fixed row slots; LM blocks are shape-preserving.  Every buffer
+    the runner carries (``micro``, ``buf``, ``outs``) has that shape per
+    microbatch, so a cut ships its live set in its one ``ppermute``.
 
     The program's own steps carry ``shisha.*`` named scopes (``init``,
     ``inject``, ``emit``, ``ship``, ``gather``), which change only op-name
