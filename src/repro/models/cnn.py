@@ -40,6 +40,15 @@ from ..core.cost_model import Layer, conv_layer
 SKIP_SCOPE = "shisha.skip"
 LEAKY_SLOPE = 0.1  # Darknet's leaky ReLU
 INPUT = -1  # the tensor index of the network's input
+# a TPU tiles the two minor dims of an array (8, 128): an image held as rows
+# of 128 lanes is padded and sliced in whole rows
+CANON_LANES = 128
+# the device scope of a lane-packed layer's patch matrix
+PATCHES_SCOPE = "shisha.patches"
+# a conv over fewer input channels than this leaves the TPU's 128 lanes nearly
+# empty (and each tap's contraction as shallow): it runs as one matmul with
+# every tap in the contraction
+LANE_SPARSE_CHANNELS = 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,6 +115,13 @@ def live_sets(specs: Sequence[ConvSpec]) -> list[list[int]]:
         for t in reads(specs, i):
             last[t] = max(last.get(t, i), i)
     return [[t for t in range(INPUT, n) if t + 1 <= b <= last[t]] for b in range(n + 1)]
+
+
+def lane_packed(sp: ConvSpec) -> bool:
+    """Whether a layer runs as one matmul over its patch matrix: a conv whose
+    few input channels would leave the lanes nearly empty (in every network
+    here, its first layer)."""
+    return sp.c_in < LANE_SPARSE_CHANNELS
 
 
 def _to_layers(specs: Sequence[ConvSpec]) -> list[Layer]:
@@ -257,6 +273,69 @@ def network_layers(name: str) -> list[Layer]:
 # ---------------------------------------------------------------------------
 
 
+def _activate(y: jax.Array, sp: ConvSpec) -> jax.Array:
+    if sp.act == "relu":
+        return jax.nn.relu(y)
+    if sp.act == "leaky":
+        return jax.nn.leaky_relu(y, LEAKY_SLOPE)
+    if sp.act != "linear":
+        raise ValueError(f"{sp.name}: unknown activation {sp.act!r}")
+    return y
+
+
+def patch_matmul(x: jax.Array, p: dict[str, jax.Array], sp: ConvSpec, padding) -> jax.Array:
+    """A lane-packed layer, bias and activation, as one matmul over its patch
+    matrix, with every tap and channel in the contraction.
+
+    One row of the patch matrix serves ``g`` adjacent output pixels (as many
+    as fill 128 lanes with their k channels and divide w_out), so that the
+    matmul's output fills the lanes: its weight holds the layer's (r, s, c,
+    k) weight once for each of the g pixels, shifted by the stride and zero
+    elsewhere in the window of ``nb`` blocks of ``u = stride * g`` input
+    pixels.  The matrix is built under the ``shisha.patches`` scope, without
+    a strided slice: ``stride`` adjacent image rows go side by side in one
+    long row, which is transposed once so that the output's rows lie in the
+    lanes; each tap row ``dr`` is then a contiguous slice of blocks and rows.
+    """
+    st, ho, c, k = sp.stride, sp.h_out, sp.c_in, sp.k
+    g = max(1, CANON_LANES // k)
+    while sp.w_out % g:
+        g -= 1
+    wq, u = sp.w_out // g, st * g
+    nb = -(-(st * (g - 1) + sp.s) // u)
+    b, h, w = x.shape[:3]
+    if padding == "SAME":
+        padding = jax.lax.padtype_to_pads((h, w), (sp.r, sp.s), (st, st), "SAME")
+    (top, _), (left, _) = padding
+    hh, nw = (sp.r - 1) // st + ho, wq + nb - 1  # rows of st image rows, blocks of an image row
+    with jax.named_scope(PATCHES_SCOPE):
+        xf = jax.lax.pad(
+            x.reshape(b, h, w * c),
+            jnp.zeros((), x.dtype),
+            [(0, 0, 0), (top, st * hh - h - top, 0), (left * c, (u * nw - w - left) * c, 0)],
+        )
+        # [u*c, B, st*nw, hh]: (block pixel, channel), image, (row parity, block), row pair
+        xt = xf.reshape(b, hh, st * nw * u * c).transpose(0, 2, 1).reshape(b, st * nw, u * c, hh).transpose(2, 0, 1, 3)
+        rows = [
+            xt[:, :, (dr % st) * nw + j : (dr % st) * nw + j + wq, dr // st : dr // st + ho].reshape(u * c, -1)
+            for dr in range(sp.r)
+            for j in range(nb)
+        ]
+        patches = jnp.concatenate(rows, axis=0)  # [r*nb*u*c, B*wq*ho]
+    # output pixel q of a group reads window pixels st*q ... st*q + s - 1
+    blocks = []
+    for q in range(g):
+        before = jnp.zeros((sp.r, st * q, c, k), x.dtype)
+        after = jnp.zeros((sp.r, nb * u - sp.s - st * q, c, k), x.dtype)
+        blocks.append(jnp.concatenate([before, p["w"], after], axis=1))
+    # where the weight is a constant, XLA would split a matmul over a
+    # concatenation into one small matmul per tap block; the barrier keeps it one
+    wg = jax.lax.optimization_barrier(jnp.stack(blocks, axis=-2).reshape(-1, g * k))
+    y = jax.lax.dot_general(patches, wg, (((0,), (0,)), ((), ())))
+    y = _activate(y + jnp.tile(p["b"], g), sp)
+    return y.reshape(b, wq, ho, g * k).transpose(0, 2, 1, 3).reshape(b, ho, sp.w_out, k)
+
+
 @dataclasses.dataclass(frozen=True)
 class CNNModel:
     """A runnable conv network (inference) matching a spec table.
@@ -300,7 +379,9 @@ class CNNModel:
 
             if padding != "SAME" and sp.stride > 1:
                 raise ValueError(f"{sp.name}: the im2col kernel pads 'SAME' only")
-            y = ops.conv2d_im2col(x, p["w"], stride=sp.stride)
+            y = _activate(ops.conv2d_im2col(x, p["w"], stride=sp.stride) + p["b"], sp)
+        elif lane_packed(sp):
+            y = patch_matmul(x, p, sp, padding)
         else:
             y = jax.lax.conv_general_dilated(
                 x,
@@ -309,13 +390,7 @@ class CNNModel:
                 padding=padding,
                 dimension_numbers=("NHWC", "HWIO", "NHWC"),
             )
-        y = y + p["b"]
-        if sp.act == "relu":
-            y = jax.nn.relu(y)
-        elif sp.act == "leaky":
-            y = jax.nn.leaky_relu(y, LEAKY_SLOPE)
-        elif sp.act != "linear":
-            raise ValueError(f"{sp.name}: unknown activation {sp.act!r}")
+            y = _activate(y + p["b"], sp)
         if sp.add is not None:
             with jax.named_scope(SKIP_SCOPE):
                 y = y + xs[n_src]
@@ -334,11 +409,6 @@ class CNNModel:
             acts = {t: acts[t] for t in live[i + 1]}
         outs = tuple(acts[t] for t in outputs(specs))
         return outs[0] if len(outs) == 1 else outs
-
-
-# a TPU tiles the two minor dims of an array (8, 128): an image held as rows
-# of 128 lanes is padded and sliced in whole rows
-CANON_LANES = 128
 
 
 def _rows(n: int) -> int:

@@ -20,7 +20,7 @@ import pytest
 
 from repro.core import PipelineConfig, paper_platform
 from repro.launch.mesh import make_stage_mesh
-from repro.models.cnn import canonical_pipeline_apply, make_cnn, network_layers
+from repro.models.cnn import PATCHES_SCOPE, canonical_pipeline_apply, make_cnn, network_layers
 from repro.pipeline import MeasuringEvaluator, PipelineRunner
 
 REPO = Path(__file__).resolve().parents[1]
@@ -52,7 +52,8 @@ def test_runner_program_carries_its_scopes(devices):
         ref = jnp.stack([model(params, raw[i]) for i in range(3)])
         assert np.allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4), "pipeline != sequential"
         """
-    assert on_host_devices(devices, code) == str(sorted(RUNNER_SCOPES))
+    # synthnet's first layer (3 input channels) builds its patch matrix too
+    assert on_host_devices(devices, code) == str(sorted(RUNNER_SCOPES + (PATCHES_SCOPE,)))
 
 
 @pytest.mark.parametrize("devices", [1, 4])

@@ -80,17 +80,10 @@ def test_conv_kernel_refuses_stride_two_stem(one_chip):
         jax.jit(functools.partial(ops.conv2d_im2col, stride=2)).lower(x, w)
 
 
-@pytest.mark.parametrize("n_stages", [1, 4])
-def test_resnet50_pipeline_fits_one_chip(topo, n_stages):
-    """The full-width 224x224 PipelineRunner program, 8 microbatches of 1 image.
-
-    Its buffers hold each image as the flat canonical, so the program is about
-    the weights (91 MB) and 8 of ResNet-50's largest activations (3.2 MB each)
-    in and out.  Inside a stage each layer's pad meets the next layer's slice
-    and folds away: a stage keeps at most four of the canonical's pads (its
-    output's, to whole rows and to all rows, and copies the compiler makes
-    of them or merges with the first conv's padding), not two a layer.
-    """
+@functools.lru_cache(maxsize=None)
+def compiled_resnet50_runner(topo, n_stages):
+    """The full-width 224x224 PipelineRunner program, 8 microbatches of 1
+    image, compiled once for each stage count."""
     model = make_cnn("resnet50")
     in_shape = (224, 224, 3)
     platform = tpu_platform_from_mesh(4, chips_per_stage=1)
@@ -109,7 +102,31 @@ def test_resnet50_pipeline_fits_one_chip(topo, n_stages):
         return PipelineRunner(mesh=mesh, conf=conf, apply_layer=apply_fn, n_micro=8).run(micro)
 
     with jax.default_matmul_precision("highest"):
-        compiled = jax.jit(program).lower(params, micro).compile()
+        return jax.jit(program).lower(params, micro).compile()
+
+
+def conv_input_features(text: str) -> list[int]:
+    """The input feature dimension of every convolution in compiled HLO text."""
+    shapes = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
+    features = []
+    for lhs, labels in re.findall(r" convolution\(%([\w.\-]+), .*?dim_labels=(\w+)_", text):
+        dims = [int(d) for d in shapes[lhs].split(",")]
+        features.append(dims[labels.index("f")])
+    return features
+
+
+@pytest.mark.parametrize("n_stages", [1, 4])
+def test_resnet50_pipeline_fits_one_chip(topo, n_stages):
+    """The full-width 224x224 PipelineRunner program, 8 microbatches of 1 image.
+
+    Its buffers hold each image as the flat canonical, so the program is about
+    the weights (91 MB) and 8 of ResNet-50's largest activations (3.2 MB each)
+    in and out.  Inside a stage each layer's pad meets the next layer's slice
+    and folds away: a stage keeps at most four of the canonical's pads (its
+    output's, to whole rows and to all rows, and copies the compiler makes
+    of them or merges with the first conv's padding), not two a layer.
+    """
+    compiled = compiled_resnet50_runner(topo, n_stages)
     mem = compiled.memory_analysis()
     per_device = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
     assert per_device < RUNNER_BYTES, (n_stages, per_device)
@@ -117,3 +134,15 @@ def test_resnet50_pipeline_fits_one_chip(topo, n_stages):
     assert len(re.findall(r"= \S+ pad\(.*shisha\.canon", text)) <= 4 * n_stages
     if n_stages > 1:
         assert "collective-permute" in text
+
+
+def test_resnet50_runner_has_no_lane_sparse_conv(topo):
+    """The stem's 3 input channels go into one matmul's contraction with its
+    taps (252 rows: 7 tap rows of 12 pixels by 3 channels, for 2 output
+    pixels a row): no convolution of the 1-stage program reads 3 features,
+    and the program still fits."""
+    compiled = compiled_resnet50_runner(topo, 1)
+    features = conv_input_features(compiled.as_text())
+    assert len(features) >= 50 and 3 not in features, sorted(set(features))
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes < RUNNER_BYTES
