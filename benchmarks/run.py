@@ -43,7 +43,7 @@ def main() -> None:
     args = ap.parse_args()
 
     from . import fig4_convergence, fig5_quality, fig6_seed, fig7_heuristics, fig9_latency
-    from . import chaos_sweep, fig9_interconnect, kernels_bench, power_sweep, roofline
+    from . import chaos_sweep, fig9_interconnect, power_sweep
     from . import serve_sim
 
     figures = {
@@ -54,8 +54,6 @@ def main() -> None:
         "fig9": fig9_latency.run,
         "fig9_interconnect": lambda: fig9_interconnect.run(quick=args.quick),
         "fig9_adaptive": lambda: fig9_interconnect.run_adaptive(quick=args.quick),
-        "kernels": kernels_bench.run,
-        "roofline": roofline.run,
         "serve_sim": lambda: serve_sim.run(quick=args.quick),
         "multitenant_drift": lambda: serve_sim.run_multitenant_drift(quick=args.quick),
         "power_sweep": lambda: power_sweep.run(quick=args.quick),

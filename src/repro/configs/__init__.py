@@ -1,16 +1,19 @@
 """Architecture & shape registry: ``--arch <id> --shape <cell>``.
 
-10 assigned architectures × 4 input-shape cells = 40 dry-run cells.
-``applicable()`` encodes the per-family skips mandated by the assignment
-(``long_500k`` needs sub-quadratic attention; enc-dec decode runs against
-its capped decoder context).  Skips are reported — never silently dropped.
+10 assigned architectures × 4 input-shape cells = 40 cells.
+``input_specs()`` describes a cell's model inputs.  ``applicable()``
+encodes the per-family skips mandated by the assignment (``long_500k``
+needs sub-quadratic attention; enc-dec decode runs against its capped
+decoder context).  Skips are reported — never silently dropped.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import importlib
-from typing import Any
+
+import jax
+import jax.numpy as jnp
 
 from ..models.lm_common import LMConfig
 
@@ -76,6 +79,39 @@ def for_shape(cfg: LMConfig, shape: str) -> LMConfig:
         # zamba2's shared attention runs a sliding window at long context
         return dataclasses.replace(cfg, sliding_window=4_096)
     return cfg
+
+
+def input_specs(cfg: LMConfig, shape_name: str, cell=None) -> dict:
+    """ShapeDtypeStruct stand-ins for every model input of this cell."""
+    cell = cell or SHAPES[shape_name]
+    B, S = cell.global_batch, cell.seq_len
+    i32 = jnp.int32
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    if cfg.is_encdec:
+        dec = min(S, cfg.max_decoder_len or S)
+        batch = {
+            "frames": sds((B, cfg.enc_frames, cfg.d_model), cfg.dtype),
+            "tokens": sds((B, dec), i32),
+        }
+        if cell.phase == "train":
+            batch["labels"] = sds((B, dec), i32)
+        return batch
+    if cfg.n_patches and cell.phase != "decode":
+        s_text = S - cfg.n_patches
+        batch = {
+            "tokens": sds((B, s_text), i32),
+            "patch_embeds": sds((B, cfg.n_patches, cfg.d_model), cfg.dtype),
+        }
+        if cell.phase == "train":
+            batch["labels"] = sds((B, s_text), i32)
+        return batch
+    batch = {"tokens": sds((B, S), i32)}
+    if cell.phase == "train":
+        batch["labels"] = sds((B, S), i32)
+    return batch
 
 
 def cells(include_skips: bool = False):

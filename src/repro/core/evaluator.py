@@ -24,6 +24,9 @@ oracle is therefore pluggable here:
   * :class:`MeasuringEvaluator` (in ``pipeline/runtime.py``) — times the
     real JAX pipeline; the true "online" mode.
 
+All three share one stage-time model, :meth:`AnalyticEvaluator.stage_times`;
+each supplies only its per-(layer, EP) time, ``layer_time_by_index``.
+
 Every evaluator is wrapped in :class:`Trace` by the exploration drivers to
 account configurations tried and *simulated wall-clock cost* of trying them
 (a trial costs ``measure_batches`` pipeline beats plus a reconfiguration
@@ -73,17 +76,13 @@ class AnalyticEvaluator:
         ep = self.platform.eps[ep_idx]
         return max(layer.flops / ep.flops, layer.bytes_mem / ep.mem_bw) + self.layer_overhead
 
-    def layer_time(self, layer: Layer, ep_idx: int) -> float:
-        t = self.nominal_layer_time(layer, ep_idx)
-        pm = self.platform.power
-        if pm is not None:
-            # DVFS scales the EP's compute rate and memory bandwidth
-            # together, so the whole on-EP time divides by the level's
-            # scale (exactly 1.0 at nominal: the no-power path is
-            # reproduced bit-for-bit).  Link transfers are unscaled — the
-            # interconnect runs on its own clock.
-            t = t / pm.scale(ep_idx)
-        return t
+    def layer_time_by_index(self, layer_idx: int, ep_idx: int) -> float:
+        """Layer ``layer_idx``'s time on EP ``ep_idx`` at its nominal clock.
+
+        The one per-layer time :meth:`stage_times` sums; each evaluator
+        overrides this method and nothing else of the stage-time model.
+        """
+        return self.nominal_layer_time(self.layers[layer_idx], ep_idx)
 
     def transfer_times(self, conf: PipelineConfig) -> list[float]:
         """Inter-stage transfer time per stage boundary (s -> s+1).
@@ -116,11 +115,20 @@ class AnalyticEvaluator:
         return fabric.flow_times(flows + list(self.background_flows))[:n_links]
 
     def stage_times(self, conf: PipelineConfig) -> list[float]:
+        """Each stage's beat: its layers' times, then the link to the next."""
         times = []
         link = self.transfer_times(conf)
+        pm = self.platform.power
         for s, (a, b) in enumerate(conf.boundaries()):
             ep_idx = conf.eps[s]
-            t = sum(self.layer_time(self.layers[i], ep_idx) for i in range(a, b))
+            t = sum(self.layer_time_by_index(i, ep_idx) for i in range(a, b))
+            if pm is not None:
+                # DVFS scales the EP's compute rate and memory bandwidth
+                # together, so the whole on-EP time divides by the level's
+                # scale (exactly 1.0 at nominal: the no-power path is
+                # reproduced bit-for-bit).  Link transfers are unscaled — the
+                # interconnect runs on its own clock.
+                t = t / pm.scale(ep_idx)
             if s < conf.depth - 1:
                 t += link[s]
             times.append(t)
@@ -161,30 +169,12 @@ class DatabaseEvaluator(AnalyticEvaluator):
             for ei in range(self.platform.n_eps):
                 # DB entries are nominal-clock times: the database is
                 # measured once, while DVFS levels move during tuning, so
-                # the scale is applied at query time (see stage_times)
+                # stage_times applies the scale at query time
                 base = AnalyticEvaluator.nominal_layer_time(self, layer, ei)
                 self._db[(li, ei)] = base * _noise(f"{layer.name}|{self.platform.eps[ei].name}", self.noise_sigma)
 
     def layer_time_by_index(self, layer_idx: int, ep_idx: int) -> float:
-        t = self._db[(layer_idx, ep_idx)]
-        pm = self.platform.power
-        if pm is not None:
-            t = t / pm.scale(ep_idx)
-        return t
-
-    def stage_times(self, conf: PipelineConfig) -> list[float]:
-        times = []
-        link = self.transfer_times(conf)
-        pm = self.platform.power
-        for s, (a, b) in enumerate(conf.boundaries()):
-            ep_idx = conf.eps[s]
-            t = sum(self._db[(i, ep_idx)] for i in range(a, b))
-            if pm is not None:
-                t = t / pm.scale(ep_idx)
-            if s < conf.depth - 1:
-                t += link[s]
-            times.append(t)
-        return times
+        return self._db[(layer_idx, ep_idx)]
 
 
 # ---------------------------------------------------------------------------

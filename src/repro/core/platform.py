@@ -287,7 +287,7 @@ def paper_platform(n_eps: int = 8, fep_fraction: float = 0.5) -> Platform:
 # TPU presets (hardware adaptation, DESIGN.md §2)
 # ---------------------------------------------------------------------------
 
-#: per-chip peak numbers used across the framework (also in benchmarks/roofline.py)
+#: per-chip peak numbers used across the framework
 TPU_PEAK_FLOPS = 197e12  # bf16 FLOP/s per chip
 TPU_HBM_BW = 819e9  # bytes/s per chip
 TPU_ICI_BW = 50e9  # bytes/s per link

@@ -1,1 +1,1 @@
-"""Launchers: production meshes, dry-run driver, training / serving CLIs."""
+"""Launchers: the pipeline stage mesh, the compile cache, training / serving CLIs."""

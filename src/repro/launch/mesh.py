@@ -1,16 +1,11 @@
-"""Production meshes.
+"""The pipeline's stage mesh.
 
-Everything is a FUNCTION — importing this module never touches jax device
-state (the dry-run driver must set XLA_FLAGS before first jax init).
-
-Single pod:  (16, 16)        axes ("data", "model")        = 256 chips.
-Multi-pod:   (2, 16, 16)     axes ("pod", "data", "model") = 512 chips.
-
-The ``pod`` axis is pure data parallelism over the slow inter-pod link
-(DCI); ``data`` is FSDP+DP inside a pod; ``model`` is tensor parallelism
-on the fastest (ICI ring) axis.  When Shisha drives pipeline parallelism
-(pipeline/runtime.py) the ``pod`` — or a dedicated ``stage`` — axis is the
-chiplet axis the paper schedules over.
+A function, so importing this module never touches jax device state.
+``make_stage_mesh(n_stages, per_stage)`` lays the first ``n_stages *
+per_stage`` devices out as a ("stage", "inner") mesh: one row of chips per
+pipeline stage, the chiplet axis the paper schedules over.
+``PipelineRunner`` (pipeline/runtime.py) runs one stage on each row and
+ships activations along "stage" with ``ppermute``.
 """
 
 from __future__ import annotations
@@ -18,20 +13,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = int(np.prod(shape))
-    devices = jax.devices()
-    if len(devices) < n:
-        raise RuntimeError(
-            f"need {n} devices for mesh {shape}, have {len(devices)} — "
-            "run under XLA_FLAGS=--xla_force_host_platform_device_count=512 (dry-run only)"
-        )
-    return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+from jax.sharding import Mesh
 
 
 def make_stage_mesh(n_stages: int, per_stage: int = 1) -> Mesh:
@@ -41,18 +23,3 @@ def make_stage_mesh(n_stages: int, per_stage: int = 1) -> Mesh:
     if len(devices) < n:
         raise RuntimeError(f"need {n} devices, have {len(devices)}")
     return Mesh(np.asarray(devices[:n]).reshape(n_stages, per_stage), ("stage", "inner"))
-
-
-def make_test_mesh(shape=(1, 1), axes=("data", "model")) -> Mesh:
-    """Tiny mesh over however many (host) devices tests have."""
-    n = int(np.prod(shape))
-    return Mesh(np.asarray(jax.devices()[:n]).reshape(shape), axes)
-
-
-def dp_axes_of(mesh: Mesh) -> tuple[str, ...]:
-    """Batch axes: everything except the TP axis."""
-    return tuple(a for a in mesh.axis_names if a != "model")
-
-
-def batch_sharding(mesh: Mesh, ndim: int) -> NamedSharding:
-    return NamedSharding(mesh, P(dp_axes_of(mesh), *([None] * (ndim - 1))))

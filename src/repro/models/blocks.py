@@ -76,7 +76,7 @@ def _sdpa(cfg: LMConfig, q, k, v, *, causal: bool, q_offset: jax.Array | int = 0
     The q axis is swept in ``cfg.attn_q_block`` chunks under lax.scan with a
     rematerialized body, so live score buffers stay O(bq·skv) — this is the
     XLA stand-in for the Pallas flash kernel (kernels/flash_attention.py),
-    with the same asymptotic memory behaviour on the dry-run roofline.
+    with the same asymptotic memory behaviour.
     """
     b, sq, h, d = q.shape
     skv, kvh = k.shape[1], k.shape[2]

@@ -7,9 +7,10 @@ Two views of each network:
   1. ``*_layers()`` — the per-layer Eq.-1 cost tables the scheduler consumes
      (the paper's "50 compute intensive layers in ResNet50 / 52 in YOLOv3",
      §7.1).  These drive the faithful-reproduction benchmarks.
-  2. ``CNNModel`` — a runnable JAX network built from the same table
-     (Im2Col+GEMM conv operator, optionally through the Pallas kernel),
-     used by the pipeline-inference example and the live-measured oracle.
+  2. ``CNNModel`` — a runnable JAX network built from the same table, used
+     by the pipeline runtime and the live-measured oracle.  Every layer is
+     an XLA conv, except a lane-sparse one (fewer than 8 input channels:
+     each network's first), which runs as one matmul over its patch matrix.
 
 SynthNet is the paper's synthetic 18-layer network: AlexNet's five conv
 layers replicated (channels chained across repeats) to reach 18 layers.
@@ -357,7 +358,7 @@ class CNNModel:
             params.append({"w": w / np.sqrt(fan_in), "b": jnp.zeros((sp.k,), jnp.float32)})
         return params
 
-    def apply_layer(self, i: int, p: dict[str, jax.Array], *xs: jax.Array, use_pallas: bool = False) -> jax.Array:
+    def apply_layer(self, i: int, p: dict[str, jax.Array], *xs: jax.Array) -> jax.Array:
         """Layer i on the tensors it ``reads``: its sources, then its shortcut's.
 
         Route concats, the shortcut add and the output's upsample carry the
@@ -374,13 +375,7 @@ class CNNModel:
         if x.shape[1] != in_h or x.shape[3] != sp.c_in:
             x = jax.image.resize(x, (x.shape[0], in_h, in_h, sp.c_in), "nearest")
         padding = {"same": "SAME", "darknet": [(sp.r // 2, sp.r // 2), (sp.s // 2, sp.s // 2)]}[sp.pad]
-        if use_pallas:
-            from ..kernels import ops
-
-            if padding != "SAME" and sp.stride > 1:
-                raise ValueError(f"{sp.name}: the im2col kernel pads 'SAME' only")
-            y = _activate(ops.conv2d_im2col(x, p["w"], stride=sp.stride) + p["b"], sp)
-        elif lane_packed(sp):
+        if lane_packed(sp):
             y = patch_matmul(x, p, sp, padding)
         else:
             y = jax.lax.conv_general_dilated(
@@ -399,13 +394,13 @@ class CNNModel:
                 y = jnp.repeat(jnp.repeat(y, sp.up, axis=1), sp.up, axis=2)
         return y
 
-    def __call__(self, params, x: jax.Array, **kw):
+    def __call__(self, params, x: jax.Array):
         """The whole network: its one output, or a tuple of its outputs."""
         specs = self.specs
         live = live_sets(specs)
         acts = {INPUT: x}
         for i in range(len(specs)):
-            acts[i] = self.apply_layer(i, params[i], *(acts[t] for t in reads(specs, i)), **kw)
+            acts[i] = self.apply_layer(i, params[i], *(acts[t] for t in reads(specs, i)))
             acts = {t: acts[t] for t in live[i + 1]}
         outs = tuple(acts[t] for t in outputs(specs))
         return outs[0] if len(outs) == 1 else outs

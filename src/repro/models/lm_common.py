@@ -15,9 +15,8 @@ One flexible block zoo covers all 10 assigned architectures:
     embeddings per the assignment).
 
 Parameters are stored **stacked over layers** (`[L, ...]` leading axis) so
-the layer loop is a `lax.scan` — the HLO stays small enough to compile all
-40 dry-run cells on 512 host devices, and remat policy applies per scan
-step.
+the layer loop is a `lax.scan` — the HLO stays small, and remat policy
+applies per scan step.
 
 Sharding convention (GSPMD, mesh axes ``("pod", "data", "model")``):
   * batch/sequence activations: batch over ``("pod","data")``.
@@ -81,8 +80,8 @@ class LMConfig:
     sliding_window: int = 0  # 0 => full attention
     attn_q_block: int = 256  # blockwise-attention q-chunk (memory/roofline knob)
     loss_chunk: int = 512  # chunked-xent sequence chunk
-    #: unroll every lax.scan — used by the dry-run's reduced-depth cost
-    #: compiles so XLA's cost analysis sees every loop iteration
+    #: unroll every lax.scan, so XLA's cost analysis sees every loop
+    #: iteration
     scan_unroll: bool = False
     # --- perf-iteration knobs (§Perf; defaults = paper-faithful baseline) ---
     #: Megatron-style sequence-parallel residual stream (seq over TP)

@@ -29,7 +29,7 @@ from .lm_common import LMConfig, cstr_act, dist_context, init_params, param_shar
 
 
 def _scan(cfg: LMConfig, f, init, xs):
-    """lax.scan that fully unrolls under cfg.scan_unroll (dry-run cost mode)."""
+    """lax.scan that fully unrolls under cfg.scan_unroll (cost-analysis mode)."""
     return jax.lax.scan(f, init, xs, unroll=bool(cfg.scan_unroll))
 
 # ---------------------------------------------------------------------------
@@ -435,8 +435,7 @@ def prefill_step(cfg: LMConfig, params: dict, batch: dict, mesh=None, dp_axes=("
     """Serving prefill: forward over the prompt, emitting the decode cache.
 
     Returns (last-token logits [b, vocab], cache).  The cache layout matches
-    ``init_cache(cfg, b, seq)`` so decode can continue from it directly —
-    and the dry-run's prefill cells account the real cache-write traffic.
+    ``init_cache(cfg, b, seq)`` so decode can continue from it directly.
     """
     with dist_context(mesh, dp_axes, tp_axis, seq_shard=cfg.sp_residuals):
         return _prefill_step(cfg, params, batch, mesh, dp_axes, tp_axis, max_len)

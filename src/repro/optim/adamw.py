@@ -1,7 +1,7 @@
 """Mixed-precision AdamW with cosine schedule and global-norm clipping.
 
 Pure-JAX pytree optimizer (no optax on this box).  Designed for the memory
-budget of the large dry-run cells (DESIGN.md §5): model params may be bf16;
+budget of the large LM configs: model params may be bf16;
 the optimizer keeps an fp32 master copy and (configurably) bf16 moments, so
 nemotron-4-340b's state is 10 bytes/param — the difference between fitting
 and not fitting 256×16 GB.

@@ -18,19 +18,16 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
 from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.config import PipelineConfig
-from ..core.cost_model import Layer
 from ..core.evaluator import AnalyticEvaluator
-from ..core.platform import Platform
 from .hetero import EPDerates
 
 # ---------------------------------------------------------------------------
@@ -43,10 +40,10 @@ class MeasuringEvaluator(AnalyticEvaluator):
     """`execute(conf)` backed by real measured per-layer times.
 
     Each layer's apply function is jitted and timed once per EP class
-    (block_until_ready, best of ``reps``); stage times are sums of measured
-    layer times scaled by the stage EP's derate — the live analogue of the
-    paper's gem5 database.  Inherits stage_times/throughput plumbing (link
-    cost model included) from AnalyticEvaluator.
+    (block_until_ready, best of ``reps``); a layer's time on an EP is its
+    measured time scaled by that EP's derate — the live analogue of the
+    paper's gem5 database.  Stage times, throughput and the link cost come
+    from AnalyticEvaluator over that table.
     """
 
     layer_fns: Sequence[Callable] | None = None
@@ -72,23 +69,8 @@ class MeasuringEvaluator(AnalyticEvaluator):
                         best = min(best, time.perf_counter() - t0)
                 self._measured.append(best)
 
-    def layer_time(self, layer: Layer, ep_idx: int) -> float:  # type: ignore[override]
-        li = list(self.layers).index(layer)
-        return self.derates.scale(ep_idx, self._measured[li]) + self.layer_overhead
-
-    def stage_times(self, conf: PipelineConfig) -> list[float]:
-        times = []
-        for s, (a, b) in enumerate(conf.boundaries()):
-            ep_idx = conf.eps[s]
-            t = sum(self.derates.scale(ep_idx, self._measured[i]) + self.layer_overhead for i in range(a, b))
-            if s < conf.depth - 1:
-                ep = self.platform.eps[ep_idx]
-                nxt = self.platform.eps[conf.eps[s + 1]]
-                t += self.layers[b - 1].act_bytes / min(ep.link_bw, nxt.link_bw) + max(
-                    ep.link_latency, nxt.link_latency
-                )
-            times.append(t)
-        return times
+    def layer_time_by_index(self, layer_idx: int, ep_idx: int) -> float:
+        return self.derates.scale(ep_idx, self._measured[layer_idx]) + self.layer_overhead
 
 
 # ---------------------------------------------------------------------------

@@ -2,10 +2,9 @@
 
 Multi-device behaviour needs XLA host-device-count set before jax init, so
 those cases run in subprocesses; in-process tests cover the pure helpers
-(collective parsing, input specs, sharding rules).
+(input specs, sharding rules).
 """
 
-import json
 import os
 import subprocess
 import sys
@@ -15,8 +14,7 @@ from pathlib import Path
 import jax
 import pytest
 
-from repro.configs import ARCHS, SHAPES, get_config
-from repro.launch.dryrun import input_specs, parse_collectives
+from repro.configs import ARCHS, SHAPES, get_config, input_specs
 
 REPO = Path(__file__).resolve().parents[1]
 
@@ -40,23 +38,6 @@ def _run(code: str, devices: int = 8, timeout: int = 500):
 # ---------------------------------------------------------------------------
 # Pure helpers
 # ---------------------------------------------------------------------------
-
-
-def test_parse_collectives_synthetic():
-    hlo = """
-  %ag = f32[64,128]{1,0} all-gather(%p0), channel_id=1, replica_groups=[16,16]<=[256], dimensions={0}
-  %ar = bf16[32,32]{1,0} all-reduce(%p1), replica_groups={{0,1,2,3}}, to_apply=%add
-  %done = f32[8]{0} all-reduce-done(%x)
-  %cp = f32[4,4]{1,0} collective-permute(%p2), source_target_pairs={{0,1}}
-"""
-    colls = parse_collectives(hlo)
-    ops = sorted(c["op"] for c in colls)
-    assert ops == ["all-gather", "all-reduce", "collective-permute"]
-    ag = next(c for c in colls if c["op"] == "all-gather")
-    assert ag["bytes"] == 64 * 128 * 4
-    assert ag["group"] == 16
-    ar = next(c for c in colls if c["op"] == "all-reduce")
-    assert ar["group"] == 4
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -175,16 +156,3 @@ def test_moe_local_vs_sharded_equivalence():
         devices=2,
     )
 
-
-def test_make_production_mesh_shapes():
-    _run(
-        """
-        from repro.launch.mesh import make_production_mesh
-        m1 = make_production_mesh()
-        assert m1.shape == {"data": 16, "model": 16}, m1.shape
-        m2 = make_production_mesh(multi_pod=True)
-        assert m2.shape == {"pod": 2, "data": 16, "model": 16}, m2.shape
-        print("OK")
-        """,
-        devices=512,
-    )

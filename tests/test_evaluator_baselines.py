@@ -2,6 +2,8 @@
 
 import math
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.core import (
@@ -19,7 +21,9 @@ from repro.core import (
     simulated_annealing,
     weights,
 )
-from repro.models.cnn import network_layers
+from repro.models.cnn import make_cnn, network_layers
+from repro.pipeline import MeasuringEvaluator
+from repro.power import uniform_power
 
 
 def _setup(net="synthnet", n_eps=4):
@@ -61,6 +65,42 @@ def test_database_deterministic():
     ev2 = DatabaseEvaluator(plat, layers)
     conf = PipelineConfig(stages=(10, 8), eps=(0, 2))
     assert ev1.throughput(conf) == ev2.throughput(conf)
+
+
+def _evaluator(cls, plat, layers):
+    if cls is not MeasuringEvaluator:
+        return cls(plat, layers)
+    # time the first scaled synthnet layers on the host device
+    model = make_cnn("synthnet", scale=0.1)
+    params = model.init(jax.random.PRNGKey(0))
+    first = model.specs[0]
+    hw = first.h_out * first.stride
+    xs, x = [], jnp.ones((1, hw, hw, first.c_in))
+    for i in range(len(layers)):
+        xs.append(x)
+        x = model.apply_layer(i, params[i], x)
+    fns = [lambda p, x, i=i: model.apply_layer(i, p, x) for i in range(len(layers))]
+    return cls(plat, layers, layer_fns=fns, layer_args=list(zip(params, xs)), reps=2)
+
+
+@pytest.mark.parametrize("evaluator_cls", [AnalyticEvaluator, DatabaseEvaluator, MeasuringEvaluator])
+def test_stage_times_are_the_table_over_the_stage(evaluator_cls):
+    """One stage-time model: each stage is its layers' per-index times over
+    the stage EP's DVFS scale, plus the boundary's link."""
+    layers = network_layers("synthnet")[:3]
+    plat = paper_platform(2)
+    pm = uniform_power(plat)
+    ev = _evaluator(evaluator_cls, plat.with_power(pm), layers)
+    conf = PipelineConfig(stages=(2, 1), eps=(0, 1))
+    pm.set_level(0, 2)
+    assert pm.scale(0) != 1.0
+    link = ev.transfer_times(conf)
+    want = []
+    for s, (a, b) in enumerate(conf.boundaries()):
+        ep = conf.eps[s]
+        t = sum(ev.layer_time_by_index(i, ep) for i in range(a, b)) / pm.scale(ep)
+        want.append(t + link[s] if s < conf.depth - 1 else t)
+    assert ev.stage_times(conf) == want
 
 
 def test_trace_accounts_cost_and_curve():
