@@ -4,8 +4,9 @@
 metadata, on one host device and on four, and still computes what the
 sequential model does; with the scopes left out it compiles to the same
 program once metadata is stripped; a profiled ``run`` is one ``shisha.run``
-span per call; a profiled ``MeasuringEvaluator`` build writes one warm and
-one timed span per layer, inside ``shisha.measure``.
+span per call; a profiled ``MeasuringEvaluator`` build writes, inside
+``shisha.measure``, a warm and a timed span for each layer it compiles, and
+only the timed span for each layer whose program an earlier build compiled.
 """
 
 import os
@@ -150,16 +151,19 @@ def test_measuring_evaluator_spans(tmp_path):
     def build():
         return MeasuringEvaluator(paper_platform(2), layers, layer_fns=fns, layer_args=list(zip(params, xs)), reps=2)
 
-    build()  # compiles
     jax.profiler.start_trace(str(tmp_path))
-    ev = build()
+    evs = [build(), build()]  # the first compiles, the second reuses
     jax.profiler.stop_trace()
-    assert len(ev._measured) == n and all(t > 0 for t in ev._measured)
+    for ev in evs:
+        assert len(ev._measured) == n and all(t > 0 for t in ev._measured)
     spans = host_spans(tmp_path)
-    (measure,) = [s for s in spans if s[0] == "shisha.measure"]
-    inner = [(name, meta) for name, _, _, meta in spans if name != "shisha.measure"]
-    want = []
+    measures = [s for s in spans if s[0] == "shisha.measure"]
+    assert len(measures) == 2
+    want = [[], []]
     for i in range(n):
-        want += [("shisha.measure.warm", {"layer": i}), ("shisha.measure.timed", {"layer": i, "calls": 2})]
-    assert inner == want
-    assert all(measure[1] <= a <= b <= measure[2] for _, a, b, _ in spans)
+        want[0] += [("shisha.measure.warm", {"layer": i}), ("shisha.measure.timed", {"layer": i, "calls": 2, "cached": 0})]
+        want[1] += [("shisha.measure.timed", {"layer": i, "calls": 2, "cached": 1})]
+    for measure, want_inner in zip(measures, want):
+        inner = [(name, meta) for name, a, b, meta in spans if name != "shisha.measure" and measure[1] <= a <= b <= measure[2]]
+        assert inner == want_inner
+    assert sum(name != "shisha.measure" for name, _, _, _ in spans) == 3 * n
